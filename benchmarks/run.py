@@ -44,6 +44,9 @@ def main() -> None:
                          "chrome://tracing)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (backend_ratio, code_size, fault_latency, fleet,
                    lru_accuracy, metadata, overcommit, overhead, roofline)
 

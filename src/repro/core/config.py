@@ -17,6 +17,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+from ..kernels.layout import ROW_BYTES
+
 ABI_VERSION = 1
 
 
@@ -96,8 +98,10 @@ class HotPathConfig:
     * ``pallas_kernels`` -- route the batched data path through the
       Pallas kernels in ``repro.kernels`` (zero-detect scan, Fletcher
       extent tags, gather/scatter swap copies) instead of numpy/zlib
-      host ops -- the device entry point for a TPU backend;
-      interpret-mode on CPU, so the host path stays the default. The
+      host ops. They compile on a TPU backend and run in interpret mode
+      on the CPU backend (the test lane); any other backend raises
+      (``kernels.ops.interpret_mode``). MPs must then be a multiple of
+      512 B, the row of the kernels' word layout. The
       per-MP CRC stored in MS records is zlib.crc32 on both paths
       (records stay byte-compatible, hot-upgrade ABI §4.4); the Fletcher
       checksum (kernels/crc32c.py, ops.batch_checksum) is the
@@ -355,6 +359,9 @@ class TaijiConfig:
                 raise ValueError("hot_path.extent_cache_entries must be >= 0")
             if not 0 <= getattr(hp, "remote_tier", 0) <= 1:
                 raise ValueError("hot_path.remote_tier must be 0 or 1")
+            if hp.pallas_kernels and self.mp_bytes % ROW_BYTES:
+                raise ValueError(f"pallas_kernels needs mp_bytes a multiple "
+                                 f"of {ROW_BYTES}, got {self.mp_bytes}")
         if self.obs.ring_capacity < 1 or self.obs.max_spans < 0:
             raise ValueError("obs ring_capacity must be >= 1, max_spans >= 0")
 

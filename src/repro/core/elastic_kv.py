@@ -31,9 +31,11 @@ import dataclasses
 import threading
 from typing import Dict, List, Optional, Sequence, Union
 
+import ml_dtypes
 import numpy as np
 
 from ..analysis.lock_order import named_lock
+from ..kernels.layout import ROW_BYTES
 from .config import TaijiConfig
 from .guest import GuestSpace
 from .system import TaijiSystem
@@ -46,7 +48,16 @@ class KVGeometry:
     kv_heads: int
     head_dim: int
     block_tokens: int = 16
-    dtype_bytes: int = 2        # bf16 on device
+    dtype: str = "float16"      # element type of the held K/V ("bfloat16" on device)
+
+    @property
+    def np_dtype(self) -> np.dtype:
+        return np.dtype(ml_dtypes.bfloat16 if self.dtype == "bfloat16"
+                        else self.dtype)
+
+    @property
+    def dtype_bytes(self) -> int:
+        return self.np_dtype.itemsize
 
     @property
     def block_bytes(self) -> int:
@@ -71,11 +82,12 @@ def _mpool_reserve_ms(ms_bytes: int, mps: int, n_phys: int,
 
 def make_kv_taiji_config(geom: KVGeometry, n_phys_blocks: int,
                          overcommit: float = 0.5, **overrides) -> TaijiConfig:
-    """Size a Taiji config so one MS == one KV block."""
+    """Size a Taiji config so one MS == one KV block.
+
+    An MS splits into up to 8 MPs, each a multiple of 512 B where the
+    block allows it (the row the swap kernels' word layout takes)."""
     ms_bytes = geom.block_bytes
-    mps = 8
-    while ms_bytes // mps < 512 and mps > 1:
-        mps //= 2
+    mps = next((m for m in (8, 4, 2) if ms_bytes % (m * ROW_BYTES) == 0), 1)
     reserve = _mpool_reserve_ms(ms_bytes, mps, n_phys_blocks, overcommit)
     base = dict(
         ms_bytes=ms_bytes,
@@ -155,7 +167,7 @@ class ElasticKVCache:
         expect = (g.n_layers, 2, g.kv_heads, g.head_dim)
         if kv_token.shape != expect:
             raise ValueError(f"kv shape {kv_token.shape} != {expect}")
-        raw = kv_token.astype(np.float16 if g.dtype_bytes == 2 else np.float32)
+        raw = kv_token.astype(g.np_dtype)
         with self._lock:
             t = self._tokens[seq_id]
             blocks = self._blocks[seq_id]
@@ -172,8 +184,7 @@ class ElasticKVCache:
     # ---------------------------------------------------------------- reads
     def _block_dtype_shape(self):
         g = self.geom
-        dt = np.float16 if g.dtype_bytes == 2 else np.float32
-        return dt, (g.block_tokens, g.n_layers, 2, g.kv_heads, g.head_dim)
+        return g.np_dtype, (g.block_tokens, g.n_layers, 2, g.kv_heads, g.head_dim)
 
     def read_block(self, seq_id: int, block_idx: int) -> np.ndarray:
         """Read one block back as [block_tokens, n_layers, 2, kv_heads, head_dim]."""

@@ -305,14 +305,12 @@ class GuestSpace:
         # .tolist() once: per-row numpy scalar indexing costs ~100ns a
         # touch, which would hand back most of the amortization win
         fl, bl, nl = fast.tolist(), base.tolist(), n.tolist()
-        out: List[bytes] = []
-        append = out.append
-        for i, b in enumerate(bl):
-            if fl[i]:
-                append(buf[b:b + nl[i]].tobytes())
-            else:
-                append(self._guest_read(int(g[i]) * ms_bytes + int(o[i]),
-                                        nl[i]))
+        # every fast row is copied before the first fault: a fault's
+        # reclaim may evict a later row's MS and reuse its frame
+        out: List[bytes] = [buf[b:b + nl[i]].tobytes() if fl[i] else b""
+                            for i, b in enumerate(bl)]
+        for i in np.flatnonzero(~fast).tolist():
+            out[i] = self._guest_read(int(g[i]) * ms_bytes + int(o[i]), nl[i])
         if tr is not None:
             tr.push(ST_GUEST_ACCESS, t0, _perf_ns() - t0, TAG_READ_MANY)
         if self._observers:
@@ -341,12 +339,14 @@ class GuestSpace:
         buf = self._buf
         base = self._pfn[np.where(fast, g, 0)].astype(np.int64) * ms_bytes + o
         fl, bl, nl = fast.tolist(), base.tolist(), n.tolist()
+        # fast rows first: a slow row's fault may reclaim a fast row's
+        # frame, after which its probed address belongs to another MS
         for i, (_, _, data) in enumerate(items):
             if fl[i]:
                 b = bl[i]
                 buf[b:b + nl[i]] = np.frombuffer(data, np.uint8)
-            else:
-                self._guest_write(int(g[i]) * ms_bytes + int(o[i]), data)
+        for i in np.flatnonzero(~fast).tolist():
+            self._guest_write(int(g[i]) * ms_bytes + int(o[i]), items[i][2])
         if tr is not None:
             tr.push(ST_GUEST_ACCESS, t0, _perf_ns() - t0, TAG_WRITE_MANY)
         if self._observers:
@@ -382,14 +382,15 @@ class GuestSpace:
         raw = np.empty((g.size, nbytes), np.uint8)
         base = self._pfn[np.where(fast, g, 0)].astype(np.int64) * ms_bytes + off
         fl, bl, gl = fast.tolist(), base.tolist(), g.tolist()
+        # every fast row is copied before the first fault: a fault's
+        # reclaim may evict a later row's MS and reuse its frame
         for i in range(g.size):
             if fl[i]:
                 b = bl[i]
                 raw[i] = self._buf[b:b + nbytes]
-            else:
-                raw[i] = np.frombuffer(
-                    self._guest_read(gl[i] * ms_bytes + off, nbytes),
-                    np.uint8)
+        for i in np.flatnonzero(~fast).tolist():
+            raw[i] = np.frombuffer(
+                self._guest_read(gl[i] * ms_bytes + off, nbytes), np.uint8)
         if tr is not None:
             tr.push(ST_GUEST_ACCESS, t0, _perf_ns() - t0, TAG_GATHER)
         if self._observers:
@@ -422,13 +423,14 @@ class GuestSpace:
         ms_bytes = self._ms_bytes
         base = self._pfn[np.where(fast, g, 0)].astype(np.int64) * ms_bytes + off
         fl, bl, gl = fast.tolist(), base.tolist(), g.tolist()
+        # fast rows first: a slow row's fault may reclaim a fast row's
+        # frame, after which its probed address belongs to another MS
         for i in range(g.size):
             if fl[i]:
                 b = bl[i]
                 self._buf[b:b + nbytes] = rows[i]
-            else:
-                self._guest_write(gl[i] * ms_bytes + off,
-                                  rows[i].tobytes())
+        for i in np.flatnonzero(~fast).tolist():
+            self._guest_write(gl[i] * ms_bytes + off, rows[i].tobytes())
         if tr is not None:
             tr.push(ST_GUEST_ACCESS, t0, _perf_ns() - t0, TAG_SCATTER)
         if self._observers:

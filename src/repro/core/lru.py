@@ -139,29 +139,34 @@ class MultiLevelLRU:
         self._level_of[gfn] = dst
 
     # ------------------------------------------------------------ selection
-    def pick_cold(self, n: int, include_cold_int: bool = False) -> List[int]:
-        """Coldest-first reclaim candidates (head of the COLD set first)."""
+    def pick_cold(self, n: int, include_cold_int: bool = False,
+                  skip: Optional[Callable[[int], bool]] = None) -> List[int]:
+        """Coldest-first reclaim candidates (head of the COLD set first),
+        passing over pages ``skip`` rejects (pinned ones)."""
         out: List[int] = []
         with self._lock:
             for lvl in ([COLD, COLD_INT] if include_cold_int else [COLD]):
-                it = iter(self._sets[lvl])
-                while len(out) < n:
-                    try:
-                        out.append(next(it))
-                    except StopIteration:
-                        break
-                if len(out) >= n:
-                    break
+                for gfn in self._sets[lvl]:
+                    if skip is not None and skip(gfn):
+                        continue
+                    out.append(gfn)
+                    if len(out) >= n:
+                        return out
         return out
 
-    def pick_coldest_any(self, n: int) -> List[int]:
+    def pick_coldest_any(self, n: int,
+                         skip: Optional[Callable[[int], bool]] = None
+                         ) -> List[int]:
         """Forced reclaim under critical pressure: walk from the cold end
         toward the hot end and take the relatively coldest pages (the min
-        watermark's proactive swap-out must always make progress)."""
+        watermark's proactive swap-out must always make progress, so
+        pages ``skip`` rejects -- pinned ones -- are passed over)."""
         out: List[int] = []
         with self._lock:
             for lvl in range(COLD, HOT - 1, -1):
                 for gfn in self._sets[lvl]:
+                    if skip is not None and skip(gfn):
+                        continue
                     out.append(gfn)
                     if len(out) >= n:
                         return out

@@ -98,8 +98,8 @@ class SwapEngine:
         self._tr = metrics.tracer
         if cfg.swap.use_pallas_kernels:
             # device data path for the batched MP copies: gather on
-            # swap-out, scatter on swap-in (kernels/swap_copy.py,
-            # interpret mode off-TPU so CI validates the kernel bodies)
+            # swap-out, scatter on swap-in (kernels/swap_copy.py;
+            # compiled on a TPU, interpreted on the CPU test lane)
             from ..kernels import ops as _kops
             self._kernel_gather = _kops.batch_gather
             self._kernel_scatter = _kops.batch_scatter
@@ -938,9 +938,12 @@ class SwapEngine:
             # magazine-cached slots before reporting None)
             if self._lru_pending:
                 self.drain_lru_pending()
-            cands = self.lru.pick_cold(4, include_cold_int=True)
+            # a pinned MS cannot be reclaimed: picking it again each
+            # round would spin to the deadline while others are free
+            pinned = self.virt.table.is_pinned
+            cands = self.lru.pick_cold(4, include_cold_int=True, skip=pinned)
             if not cands:
-                cands = self.lru.pick_coldest_any(4)
+                cands = self.lru.pick_coldest_any(4, skip=pinned)
             for gfn in cands:
                 try:
                     self.swap_out_ms(gfn, blocking_lock=False)

@@ -89,8 +89,7 @@ def capture_kv_serving(seed: int = 11, *, n_seqs: int = 6, turns: int = 8,
     """
     if smoke:
         turns = min(turns, 5)
-    geom = KVGeometry(n_layers=2, kv_heads=2, head_dim=16, block_tokens=4,
-                      dtype_bytes=2)
+    geom = KVGeometry(n_layers=2, kv_heads=2, head_dim=16, block_tokens=4)
     # watermarks sit high so elasticity stays active even when the trace
     # is replayed on a fleet with more aggregate physical memory than the
     # capture node (a 2-node replay still ages + reclaims)

@@ -1,8 +1,11 @@
-"""Pallas TPU kernels for the perf-critical layers (DESIGN.md §2).
+"""Pallas TPU kernels for the perf-critical layers.
 
 Each kernel module uses pl.pallas_call with explicit BlockSpec VMEM
-tiling; ops.py exposes jit'd wrappers (interpret=True off-TPU) and ref.py
-holds the pure-jnp oracles the tests sweep against.
+tiling; ops.py exposes the host entries and ``interpret_mode`` (compiled
+on TPU, interpreted on CPU), ref.py holds the pure-jnp oracles the tests
+sweep against, and layout.py the word layout of the swap kernels.
+Submodules load on import (``from repro.kernels import ops``), so the
+jax-free host core can read ``layout`` without importing JAX.
 
   zero_detect      -- zero-block detection (backend fast path, Fig 15c)
   compress         -- per-MP int8 quantize/dequantize (device swap backend)
@@ -13,4 +16,3 @@ holds the pure-jnp oracles the tests sweep against.
   paged_attention  -- decode attention walking the block table in-kernel
                       (the EPT walk on the I/O path)
 """
-from . import ops, ref  # noqa: F401

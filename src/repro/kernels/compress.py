@@ -27,7 +27,7 @@ def _quant_kernel(x_ref, q_ref, scale_ref):
 
 @functools.partial(jax.jit, static_argnames=("mps_per_block", "interpret"))
 def block_quantize(blocks: jnp.ndarray, mps_per_block: int = 8,
-                   *, interpret: bool = True):
+                   *, interpret: bool):
     """blocks: (n, elems) float -> (q int8 (n, elems), scales (n, mps) f32).
 
     Grid: (n, mps_per_block); BlockSpec tiles one MP per step.
@@ -55,7 +55,7 @@ def _dequant_kernel(q_ref, scale_ref, x_ref):
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
 def block_dequantize(q: jnp.ndarray, scales: jnp.ndarray,
-                     out_dtype=jnp.float32, *, interpret: bool = True):
+                     out_dtype=jnp.float32, *, interpret: bool):
     """Inverse kernel: (q (n, elems), scales (n, mps)) -> (n, elems)."""
     n, elems = q.shape
     mps = scales.shape[-1]

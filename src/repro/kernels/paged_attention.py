@@ -71,7 +71,7 @@ def _paged_attn_kernel(table_ref, kvlen_ref, q_ref, pool_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q: jnp.ndarray, kv_pool: jnp.ndarray,
                            block_table: jnp.ndarray, kv_len: jnp.ndarray,
-                           *, interpret: bool = True) -> jnp.ndarray:
+                           *, interpret: bool) -> jnp.ndarray:
     """q: (B, H, hd); kv_pool: (n_blocks, bt, 2, KV, hd);
     block_table: (B, mbs) i32; kv_len: (B,) i32 -> (B, H, hd).
 

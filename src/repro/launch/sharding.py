@@ -16,7 +16,6 @@ qwen2-0.5b simply leaves heads unsharded on a 16-way model axis).
 """
 from __future__ import annotations
 
-import inspect
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -27,16 +26,7 @@ from repro.models.config import ArchConfig
 
 def abstract_mesh(axis_sizes: Sequence[int],
                   axis_names: Sequence[str]) -> AbstractMesh:
-    """Version-compat ``AbstractMesh`` constructor.
-
-    jax 0.4.x takes one ``shape_tuple`` of ``(name, size)`` pairs; jax
-    0.5+ takes ``(axis_sizes, axis_names)`` positionally. Dispatch on the
-    constructor signature so rule code and tests build device-free meshes
-    the same way against either API.
-    """
-    params = tuple(inspect.signature(AbstractMesh.__init__).parameters)
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """A device-free mesh for rule code and tests."""
     return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 

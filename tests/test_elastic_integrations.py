@@ -9,8 +9,7 @@ from repro.core.elastic_kv import ElasticKVCache, KVGeometry, make_kv_taiji_conf
 from repro.core.elastic_params import ElasticExpertCache, make_expert_taiji_config
 from repro.core.system import TaijiSystem
 
-GEOM = KVGeometry(n_layers=2, kv_heads=2, head_dim=16, block_tokens=4,
-                  dtype_bytes=2)
+GEOM = KVGeometry(n_layers=2, kv_heads=2, head_dim=16, block_tokens=4)
 
 
 def make_cache(phys_blocks=8, overcommit=2.0):

@@ -305,3 +305,36 @@ def test_prefetch_async_clean_join():
         assert th.exc is None
     finally:
         s.close()
+
+
+# --------------------------------- batch ops vs. the faults they trigger
+@pytest.mark.parametrize("op", ["gather", "read_many"])
+def test_batch_read_survives_reclaim_by_its_own_fault(op):
+    """A swapped row faulting in with memory exhausted reclaims other
+    MSs -- possibly rows of the same batch already probed as resident.
+    Their frames then hold the faulted MS, so each resident row must be
+    copied before the first fault."""
+    s = TaijiSystem(small_test_config(n_phys_ms=12, mpool_reserve_ms=2))
+    try:
+        space, cfg = s.guest, s.cfg
+        gfns, data = [], {}
+        while s.phys.free_count:
+            g = space.alloc_ms()
+            data[g] = bytes([len(gfns) + 1]) * cfg.ms_bytes
+            space.write(g, data[g])
+            gfns.append(g)
+        # the oldest MS goes out; a new one takes its frame, so the
+        # fault below must reclaim resident MSs of the batch
+        s.engine.swap_out_ms(gfns[0])
+        g = space.alloc_ms()
+        data[g] = b"\xee" * cfg.ms_bytes
+        space.write(g, data[g])
+        batch = gfns[:6]
+        if op == "gather":
+            got = [row.tobytes() for row in space.gather(batch)]
+        else:
+            got = space.read_many([(g, 0, cfg.ms_bytes) for g in batch])
+        assert got == [data[g] for g in batch]
+        assert s.metrics.ms_swapped_out > 1          # the fault reclaimed
+    finally:
+        s.close()

@@ -1,6 +1,8 @@
 """Multi-level LRU (paper §4.2.1, Fig 7): transitions, smoothing, order."""
 import random
 
+import pytest
+
 try:
     import hypothesis.strategies as st
     from hypothesis import given, settings
@@ -76,6 +78,19 @@ def test_pick_cold_orders_coldest_first():
     assert lru.level_of(10) == COLD
     picked = lru.pick_cold(2)
     assert picked == [10, 11]          # arrival order = coldest first
+
+
+@pytest.mark.parametrize("pick", ["pick_cold", "pick_coldest_any"])
+def test_pickers_pass_over_skipped_pages(pick):
+    """Exhausted-memory reclaim skips pinned MSs; it must still get the
+    next candidates, not the pinned ones again."""
+    lru, bits = make(stabilize=1)
+    for g in (10, 11, 12, 13):
+        lru.track(g)
+    for _ in range(5):
+        lru.scan_shard(0, 1)
+    picked = getattr(lru, pick)(2, skip=lambda g: g in (10, 12))
+    assert picked == [11, 13]
 
 
 def test_swapin_joins_hot_set():
