@@ -101,7 +101,7 @@ LOCK_CLASSES: Dict[str, LockClass] = {c.name: c for c in (
     LockClass("sched.rq", 66, "RunQueue.lock (tasks run outside it)"),
     # -- leaves: telemetry may be recorded under anything
     LockClass("metrics", 70,
-              "leaf telemetry: latency rings, timelines, span tracer, "
+              "leaf telemetry: latency rings, span tracer, "
               "fleet trace recorder", multi=True),
 )}
 
@@ -157,7 +157,6 @@ LINT_BINDINGS: Dict[Tuple[Optional[str], str], str] = {
     ("EntryOps", "_drained"): "entry",
     ("RunQueue", "lock"): "sched.rq",
     ("LatencyRing", "_lock"): "metrics",
-    ("Timeline", "_lock"): "metrics",
     ("SpanTracer", "_lock"): "metrics",
     ("TraceRecorder", "_lock"): "metrics",
     ("DMARegistry", "_lock"): "app",

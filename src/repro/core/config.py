@@ -283,9 +283,10 @@ class BackendConfig:
 class ObsConfig:
     """Observability: stage-attributed span tracing (``repro.obs``).
 
-    Off by default: with ``enabled=False`` no ``SpanTracer`` is
-    constructed and every instrumented call site costs exactly one
-    ``is not None`` branch (the GuestSpace empty-observer discipline).
+    Off by default (serving's ``make_kv_taiji_config`` turns it on):
+    with ``enabled=False`` no ``SpanTracer`` is constructed and every
+    instrumented call site costs exactly one ``is not None`` branch (the
+    GuestSpace empty-observer discipline).
     Spans are wall-clock telemetry only -- they never enter
     ``deterministic_snapshot``, so capture/replay and chaos determinism
     are identical with tracing on or off.
@@ -293,7 +294,7 @@ class ObsConfig:
 
     enabled: bool = False
     ring_capacity: int = 4096     # encoded spans buffered between flushes
-    max_spans: int = 200_000      # retained decoded spans (Chrome export)
+    max_spans: int = 200_000      # newest decoded spans kept (export, reads)
 
 
 @dataclasses.dataclass(frozen=True)

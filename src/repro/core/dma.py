@@ -12,13 +12,17 @@ the step duration. The registry supports both long-lived application tags
 """
 from __future__ import annotations
 
+import time
 from contextlib import contextmanager
 from typing import Dict, Iterable, List, Set
 
 from ..analysis.lock_order import named_lock
+from ..obs.tracer import ST_PIN_STEP, TAG_SWAPIN_PIN
 from .metrics import Metrics
 from .swap import SwapEngine
 from .virt import NO_PFN, VirtualizationLayer
+
+_perf_ns = time.perf_counter_ns
 
 
 class DMARegistry:
@@ -27,6 +31,9 @@ class DMARegistry:
         self.virt = virt
         self.engine = engine
         self.metrics = metrics
+        # one pin_step span per step pin (swap-ins + pin, not the unpin);
+        # None when tracing is off
+        self._tr = metrics.tracer
         self._lock = named_lock("app")
         # gfn -> pin refcount (a gfn may be in several active ranges/steps)
         self._pins: Dict[int, int] = {}
@@ -54,11 +61,16 @@ class DMARegistry:
     def pin_for_step(self, gfns: Iterable[int]):
         """Pin a working set for one in-flight step (DMA cannot retry)."""
         gfns = list(gfns)
+        tr = self._tr
+        if tr is not None:
+            t0 = _perf_ns()
         for gfn in gfns:
             self._ensure_resident(gfn)
         with self._lock:
             for gfn in gfns:
                 self._pin_locked(gfn)
+        if tr is not None:
+            tr.push(ST_PIN_STEP, t0, _perf_ns() - t0)
         try:
             yield
         finally:
@@ -71,10 +83,10 @@ class DMARegistry:
         """Timely swap-in before access (§7.1)."""
         req = self.engine.reqs.lookup(gfn)
         if req is not None and req.record.swapped_out_count() > 0:
-            self.engine.swap_in_ms(gfn)
+            self.engine.swap_in_ms(gfn, tag=TAG_SWAPIN_PIN)
         if int(self.virt.table.pfn[gfn]) == NO_PFN:
             # fully swapped and no req progress -- fault in MP 0 to allocate
-            self.engine.swap_in_ms(gfn)
+            self.engine.swap_in_ms(gfn, tag=TAG_SWAPIN_PIN)
 
     def _pin_locked(self, gfn: int) -> None:
         c = self._pins.get(gfn, 0)

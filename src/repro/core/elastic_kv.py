@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Union
 
 import ml_dtypes
@@ -36,10 +37,13 @@ import numpy as np
 
 from ..analysis.lock_order import named_lock
 from ..kernels.layout import ROW_BYTES
-from .config import TaijiConfig
+from ..obs.tracer import ST_KV_ALLOC, ST_KV_APPEND
+from .config import ObsConfig, TaijiConfig
 from .guest import GuestSpace
 from .system import TaijiSystem
 from .virt import F_SPLIT, NO_PFN
+
+_perf_ns = time.perf_counter_ns
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +89,9 @@ def make_kv_taiji_config(geom: KVGeometry, n_phys_blocks: int,
     """Size a Taiji config so one MS == one KV block.
 
     An MS splits into up to 8 MPs, each a multiple of 512 B where the
-    block allows it (the row the swap kernels' word layout takes)."""
+    block allows it (the row the swap kernels' word layout takes).
+    Serving keeps the span tracer on (aggregates for ``render_prom`` and
+    window deltas in ``Metrics.snapshot``); an ``obs=`` override wins."""
     ms_bytes = geom.block_bytes
     mps = next((m for m in (8, 4, 2) if ms_bytes % (m * ROW_BYTES) == 0), 1)
     reserve = _mpool_reserve_ms(ms_bytes, mps, n_phys_blocks, overcommit)
@@ -95,6 +101,7 @@ def make_kv_taiji_config(geom: KVGeometry, n_phys_blocks: int,
         n_phys_ms=n_phys_blocks + reserve,
         mpool_reserve_ms=reserve,
         overcommit_ratio=overcommit,
+        obs=ObsConfig(enabled=True),
     )
     base.update(overrides)
     return TaijiConfig(**base)
@@ -134,6 +141,8 @@ class ElasticKVCache:
         self.geom = geom
         self.space = space.guest if isinstance(space, TaijiSystem) else space
         self.system = self.space.system      # telemetry / legacy accessors
+        # kv_append / kv_alloc spans; None when tracing is off
+        self._tr = self.system.metrics.tracer
         self._lock = named_lock("app")
         # seq_id -> list of gfns (one per block) and token count
         self._blocks: Dict[int, List[int]] = {}
@@ -167,19 +176,28 @@ class ElasticKVCache:
         expect = (g.n_layers, 2, g.kv_heads, g.head_dim)
         if kv_token.shape != expect:
             raise ValueError(f"kv shape {kv_token.shape} != {expect}")
+        tr = self._tr
+        if tr is not None:
+            t0 = _perf_ns()
         raw = kv_token.astype(g.np_dtype)
         with self._lock:
             t = self._tokens[seq_id]
             blocks = self._blocks[seq_id]
         slot = t % g.block_tokens
         if slot == 0:                      # new block needed
+            if tr is not None:
+                t_al = _perf_ns()
             gfn = self.space.alloc_ms()
+            if tr is not None:
+                tr.push(ST_KV_ALLOC, t_al, _perf_ns() - t_al)
             with self._lock:
                 blocks.append(gfn)
         gfn = blocks[t // g.block_tokens]
         self.space.write(gfn, raw.tobytes(), off=slot * raw.nbytes)
         with self._lock:
             self._tokens[seq_id] = t + 1
+        if tr is not None:
+            tr.push(ST_KV_APPEND, t0, _perf_ns() - t0)
 
     # ---------------------------------------------------------------- reads
     def _block_dtype_shape(self):

@@ -1,9 +1,8 @@
 """Runtime metrics for the elastic-memory core.
 
 The paper evaluates Taiji with fault-latency percentiles (Fig 14f / 15d),
-water-level timelines (Fig 14e / 15a), hot/cold page counts (Fig 14c/d,
-15b), backend composition (Fig 15c) and metadata utilization (Fig 13a).
-This module provides the counters/histograms those benchmarks read.
+backend composition (Fig 15c) and metadata utilization (Fig 13a). This
+module provides the counters/histograms those benchmarks read.
 
 The fault path is latency-critical (P90 < 10 us), so ``LatencyHistogram``
 records with integer bucket math only -- no allocation, no locking beyond
@@ -11,8 +10,7 @@ the GIL (single bytecode ops on ints are atomic in CPython).
 """
 from __future__ import annotations
 
-import time
-from typing import Dict, List
+from typing import Dict
 
 import numpy as np
 
@@ -224,21 +222,6 @@ class LatencyRing:
             hist.samples.extend(ns[:room].tolist())
 
 
-class Timeline:
-    """Append-only (t, value) series, e.g. free-memory water level."""
-
-    def __init__(self, maxlen: int = 100_000) -> None:
-        self._lock = named_lock("metrics")
-        self._t0 = time.perf_counter()
-        self.points: List[tuple] = []
-        self._maxlen = maxlen
-
-    def record(self, value: float) -> None:
-        with self._lock:
-            if len(self.points) < self._maxlen:
-                self.points.append((time.perf_counter() - self._t0, value))
-
-
 class Metrics:
     """All counters for one Taiji instance."""
 
@@ -289,9 +272,6 @@ class Metrics:
         self.backend_compressed_mps = 0
         self.backend_raw_bytes = 0
         self.backend_stored_bytes = 0
-
-        self.free_ms_timeline = Timeline()
-        self.hot_cold_timeline = Timeline()
 
         # stage-attributed span tracer (repro.obs) -- None unless
         # ObsConfig.enabled; instrumented call sites cache this and guard
@@ -354,7 +334,7 @@ class Metrics:
 
         Replaying the same seeded trace through a stepped (round-based)
         fleet must produce byte-identical snapshots; latency histograms
-        and timelines are inherently timing-dependent, so fleet replay
+        and spans are inherently timing-dependent, so fleet replay
         determinism is asserted over exactly this view.
         """
         self.sync()
@@ -387,8 +367,11 @@ class Metrics:
         }
 
     def snapshot(self) -> Dict[str, object]:
+        """Counters and latency summaries; with a tracer, ``"stages"``
+        too: each stage's ``{count, total_ns, by_tag}`` so far, so two
+        snapshots give a window's exact deltas."""
         self.sync()
-        return {
+        out = {
             "faults": self.faults,
             "fault_latency": self.fault_latency.snapshot(),
             "fault_latency_by_kind": {
@@ -412,3 +395,11 @@ class Metrics:
             "compressed_mps": self.backend_compressed_mps,
             "compression_ratio": self.compression_ratio(),
         }
+        if self.tracer is not None:
+            out["stages"] = {
+                name: {"count": t["count"], "total_ns": t["total_ns"],
+                       "by_tag": {tag: {"count": b["count"],
+                                        "total_ns": b["total_ns"]}
+                                  for tag, b in t["by_tag"].items()}}
+                for name, t in self.tracer.totals().items()}
+        return out

@@ -28,6 +28,8 @@ from ..analysis.lock_order import named_lock
 from ..obs.tracer import ST_SCHED_TASK
 from .config import TaijiConfig
 
+_perf_ns = time.perf_counter_ns
+
 FRONT, FCPU, BACK, IDLE = range(4)
 CLASS_NAMES = ("FRONT", "FCPU", "BACK", "IDLE")
 
@@ -229,15 +231,16 @@ class HvScheduler:
             if spent_total >= budget:
                 break
             q = quantum * t.penalty_factor
-            t0 = time.perf_counter()
+            t0 = _perf_ns()
             try:
                 more = t.fn(q)
             except Exception:
                 more = False
-            dt = time.perf_counter() - t0
+            dt_ns = _perf_ns() - t0
             tr = self._tr
             if tr is not None:
-                tr.push(ST_SCHED_TASK, int(t0 * 1e9), int(dt * 1e9), cls)
+                tr.push(ST_SCHED_TASK, t0, dt_ns, cls)
+            dt = dt_ns / 1e9
             t.runtime_s += dt
             t.runs += 1
             spent_total += dt

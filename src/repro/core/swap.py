@@ -35,7 +35,8 @@ from ..obs.tracer import (ST_BACKEND_LOAD, ST_BACKEND_STORE, ST_FAULT_ALLOC,
                           ST_FAULT_BACKEND, ST_FAULT_COPY, ST_FAULT_DESC,
                           ST_FAULT_MUTEX, ST_FAULT_READAHEAD, ST_FAULT_TOTAL,
                           ST_READAHEAD_DECODE, ST_SWAP_GATHER, ST_SWAP_IN,
-                          ST_SWAP_OUT, ST_SWAP_SCATTER)
+                          ST_SWAP_IN_ALLOC, ST_SWAP_IN_LOCK, ST_SWAP_OUT,
+                          ST_SWAP_SCATTER, TAG_SWAPIN_HINT)
 from .metrics import (FK_COMPRESSED, FK_FAST, FK_OTHER, FK_READAHEAD,
                       FK_ZERO, Metrics)
 from .ms import (H_PFN, H_PRESENT, H_STATE, K_COMPRESSED, K_FREE,
@@ -732,19 +733,28 @@ class SwapEngine:
         return done
 
     # =========================================================== Swap_in ==
-    def swap_in_ms(self, gfn: int, *, batched: Optional[bool] = None) -> int:
-        """Active prefetch swap-in of all swapped MPs of one MS."""
+    def swap_in_ms(self, gfn: int, *, batched: Optional[bool] = None,
+                   tag: int = TAG_SWAPIN_HINT) -> int:
+        """Active prefetch swap-in of all swapped MPs of one MS.
+
+        ``tag`` marks the swap_in_lock / swap_in spans: the DMA pin
+        passes ``TAG_SWAPIN_PIN``, residency hints keep the default."""
         req = self.reqs.lookup(gfn)
         if req is None:
             return 0
+        tr = self._tr
+        if tr is not None:
+            t_lk = _perf_ns()
         grant = req.rwlock.acquire_write()
         t0 = _perf_ns()
+        if tr is not None:
+            tr.push(ST_SWAP_IN_LOCK, t_lk, t0 - t_lk, tag)
         if batched is None:
             batched = self.cfg.swap.batch_enabled
         done = 0
         try:
             if batched:
-                done = self._swap_in_batched(req, gfn, grant)
+                done = self._swap_in_batched(req, gfn, grant, tag)
             else:
                 done = self._swap_in_scalar(req, gfn, grant)
         finally:
@@ -767,7 +777,7 @@ class SwapEngine:
             done += 1
         return done
 
-    def _swap_in_batched(self, req: Req, gfn: int, grant) -> int:
+    def _swap_in_batched(self, req: Req, gfn: int, grant, tag: int) -> int:
         """Prefetch swap-in in MP index-vector chunks.
 
         Mirrors ``_fault_in_locked`` chunk-wise: exactly-once first-in
@@ -801,7 +811,11 @@ class SwapEngine:
                 if len(idxs) == 0:
                     continue
                 if rec.state == MS_SWAPPED:
+                    if tr is not None:
+                        t_al = _perf_ns()
                     pfn = self._alloc_slot_critical()
+                    if tr is not None:
+                        tr.push(ST_SWAP_IN_ALLOC, t_al, _perf_ns() - t_al)
                     rec.on_first_swap_in(pfn)     # exactly-once alloc
                     self.virt.table.map_split(gfn, pfn)
                     self.lru.note_swapped_in(gfn)
@@ -856,7 +870,7 @@ class SwapEngine:
                             self.metrics.ms_swapped_in += 1
                     req.mp_cond.notify_all()
         if tr is not None:
-            tr.push(ST_SWAP_IN, t_si, _perf_ns() - t_si)
+            tr.push(ST_SWAP_IN, t_si, _perf_ns() - t_si, tag)
         return done
 
     # ===================================================== reclaim rounds ==
@@ -875,7 +889,6 @@ class SwapEngine:
         if self._lru_pending:
             self.drain_lru_pending()
         free = self._wm.publish(self.virt.free_ms)
-        self.metrics.free_ms_timeline.record(free)
         if not self.watermark.should_start_reclaim(free):
             return 0
         deadline = (time.monotonic() + budget_s) if budget_s else None
@@ -996,10 +1009,6 @@ class SwapEngine:
         return drained
 
     # ------------------------------------------------------------ utilities
-    def resident_cold_fraction(self) -> float:
-        hot, cold = self.lru.hot_count(), self.lru.cold_count()
-        return cold / (hot + cold) if (hot + cold) else 0.0
-
     def ms_fully_swapped(self, gfn: int) -> bool:
         """``True`` when every MP of ``gfn`` lives in the backend.
 

@@ -261,12 +261,6 @@ class TaijiSystem:
             return True
 
         self.scheduler.add_task(0, "reclaim", sched.BACK, reclaim)
-
-        def idle(_quantum: float) -> bool:
-            self.metrics.hot_cold_timeline.record(self.engine.resident_cold_fraction())
-            return True
-
-        self.scheduler.add_task(0, "idle-stats", sched.IDLE, idle)
         self.scheduler.start()
 
     def stop_background(self) -> None:

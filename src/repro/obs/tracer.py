@@ -1,4 +1,5 @@
-"""Stage-attributed span tracing for the swap path.
+"""Stage-attributed span tracing for the swap path, the DMA pin, the
+elastic KV append and hv_sched's background tasks.
 
 The headline latency distributions (BENCH_smoke.json) say *what* the
 fault/swap path costs; this module says *where*. A :class:`SpanTracer`
@@ -13,7 +14,9 @@ Discipline when disabled: every instrumented call site caches
 with ``if tr is not None:`` -- the same single-truthiness-branch cost as
 the empty-observer check in ``GuestSpace``. Spans are wall-clock
 telemetry and never enter ``deterministic_snapshot``; capture/replay and
-chaos determinism are untouched by tracing.
+chaos determinism are untouched by tracing. Spans are stamped with
+``time.perf_counter_ns``; :func:`map_clock` maps them onto another
+clock (a profiler trace's) from two instants read on both.
 
 Stages form a *static* tree (``STAGES`` below): self-time rollup
 subtracts each stage's declared children from its total instead of
@@ -60,13 +63,25 @@ STAGES: Tuple[Tuple[str, Optional[str]], ...] = (
     ("swap_compress", "backend_store"),    # compress fan-out (issuer wall)
     ("kernel_store", "backend_store"),     # pallas zero-scan / extent tags
     ("backend_remote_put", "backend_store"),   # remote-peer tier replica put
+    # DMA pin of a step's working set: swap-ins + pin, on entry.
+    # swap_in / swap_in_lock spans tagged TAG_SWAPIN_PIN come from here;
+    # TAG_SWAPIN_HINT ones (touch, prefetch) nest in no pin_step
+    ("pin_step", None),
+    ("swap_in_lock", "pin_step"),          # swap-in's write-lock wait
     # SwapEngine batched swap-in pipeline
-    ("swap_in", None),
+    ("swap_in", "pin_step"),
+    ("swap_in_alloc", "swap_in"),          # first-in slot alloc (+ critical
+                                           # sync reclaim when below min)
     ("backend_load", "swap_in"),           # load_batch wall time
     ("swap_decompress", "backend_load"),   # extent/blob decompress
     ("kernel_load", "backend_load"),       # pallas scatter dispatch
     ("backend_remote_get", "backend_load"),    # remote-peer tier replica get
     ("swap_scatter", "swap_in"),           # decoded rows -> guest MPs
+    # ElasticKVCache.append_kv; the guest write inside it is a
+    # guest_access span (parent node_call), so it stays kv_append self time
+    ("kv_append", None),
+    ("kv_alloc", "kv_append"),             # a new block: slot alloc, sync
+                                           # reclaim, frame zero-fill
     # hv_sched task execution (tag = priority class)
     ("sched_task", None),
     # fleet control plane
@@ -105,12 +120,17 @@ ST_BACKEND_STORE = _IDX["backend_store"]
 ST_SWAP_COMPRESS = _IDX["swap_compress"]
 ST_KERNEL_STORE = _IDX["kernel_store"]
 ST_BACKEND_REMOTE_PUT = _IDX["backend_remote_put"]
+ST_PIN_STEP = _IDX["pin_step"]
+ST_SWAP_IN_LOCK = _IDX["swap_in_lock"]
 ST_SWAP_IN = _IDX["swap_in"]
+ST_SWAP_IN_ALLOC = _IDX["swap_in_alloc"]
 ST_BACKEND_LOAD = _IDX["backend_load"]
 ST_SWAP_DECOMPRESS = _IDX["swap_decompress"]
 ST_KERNEL_LOAD = _IDX["kernel_load"]
 ST_SWAP_SCATTER = _IDX["swap_scatter"]
 ST_BACKEND_REMOTE_GET = _IDX["backend_remote_get"]
+ST_KV_APPEND = _IDX["kv_append"]
+ST_KV_ALLOC = _IDX["kv_alloc"]
 ST_SCHED_TASK = _IDX["sched_task"]
 ST_FLEET_TICK = _IDX["fleet_tick"]
 ST_FLEET_RECOVERY = _IDX["fleet_recovery"]
@@ -124,6 +144,8 @@ TAG_READ, TAG_WRITE, TAG_READ_MANY, TAG_WRITE_MANY, TAG_GATHER, TAG_SCATTER = \
     range(6)
 ACCESS_TAG_NAMES = ("read", "write", "read_many", "write_many",
                     "gather", "scatter", "tag6", "tag7")
+# swap_in / swap_in_lock tags: under a DMA pin, or a residency hint
+TAG_SWAPIN_PIN, TAG_SWAPIN_HINT = range(2)
 # fault_total spans reuse the FK kind codes (metrics.FK_*) as tags, with
 # bit 2 carrying FK_FAST -- tags 0..7 decode to kind = tag & 3
 FAULT_TAG_NAMES = ("zero", "compressed", "readahead", "other",
@@ -142,8 +164,10 @@ class SpanTracer:
     flush zeroes the encoded slots it copied and skips ``enc == 0``.
 
     Aggregates (count / total / max per (stage, tag)) and a bounded
-    retained-span store (for Chrome-trace export) are folded under
-    ``_lock`` in :meth:`flush`.
+    retained-span store (for Chrome-trace export and clock-mapped reads)
+    are folded under ``_lock`` in :meth:`flush`. The store keeps the
+    newest ``max_spans`` spans; ``dropped_spans`` counts the older ones
+    it let go. Aggregates never drop.
     """
 
     __slots__ = ("_enc", "_t0", "_tid", "_pos", "_cap", "_lock",
@@ -203,15 +227,23 @@ class SpanTracer:
             np.add.at(self._count, (stage, tag), 1)
             np.add.at(self._total, (stage, tag), dur)
             np.maximum.at(self._max, (stage, tag), dur)
-            room = self.max_spans - self._kept
-            if room > 0:
-                k = min(room, len(enc))
-                self._chunks.append((stage[:k], t0[:k], dur[:k],
-                                     tag[:k], tid[:k]))
-                self._kept += k
-                self.dropped_spans += len(enc) - k
-            else:
-                self.dropped_spans += len(enc)
+            # the retained store keeps the newest max_spans spans: the
+            # oldest chunks go first, then the head of the oldest left
+            self._chunks.append((stage, t0, dur, tag, tid))
+            self._kept += len(enc)
+            over = self._kept - self.max_spans
+            while over > 0:
+                oldest = self._chunks[0]
+                n = len(oldest[0])
+                if n <= over:
+                    self._chunks.pop(0)
+                    cut = n
+                else:
+                    self._chunks[0] = tuple(a[over:] for a in oldest)
+                    cut = over
+                self._kept -= cut
+                self.dropped_spans += cut
+                over -= cut
 
     # ------------------------------------------------------------ accessors
     @property
@@ -243,18 +275,36 @@ class SpanTracer:
                          "by_tag": tags}
         return out
 
+    def span_arrays(self) -> Tuple[np.ndarray, ...]:
+        """Retained spans as five int64 arrays, oldest first: stage id,
+        t0_ns, dur_ns, tag, tid."""
+        self.flush()
+        with self._lock:
+            chunks = list(self._chunks)
+        if not chunks:
+            return tuple(np.zeros(0, dtype=np.int64) for _ in range(5))
+        return tuple(np.concatenate(col) for col in zip(*chunks))
+
     def spans(self) -> Iterable[Tuple[int, int, int, int, int]]:
         """Decoded retained spans: (stage_id, t0_ns, dur_ns, tag, tid)."""
-        self.flush()
-        for stage, t0, dur, tag, tid in self._chunks:
-            for i in range(len(stage)):
-                yield (int(stage[i]), int(t0[i]), int(dur[i]),
-                       int(tag[i]), int(tid[i]))
+        return zip(*(col.tolist() for col in self.span_arrays()))
 
     def export_chrome(self, path: str) -> int:
         """Write this tracer's spans as Chrome-trace JSON. See
         :func:`export_chrome`."""
         return export_chrome(path, [self])
+
+
+# ------------------------------------------------------------ clock mapping
+def map_clock(t_ns, anchor_a: Tuple[float, float],
+              anchor_b: Tuple[float, float]) -> np.ndarray:
+    """Map ``perf_counter_ns`` times onto another clock (a profiler's
+    time base), linearly through two anchor pairs ``(perf_ns, other)``
+    read at the same instants. A span's end maps like its start, so
+    ``map_clock(t0 + dur) - map_clock(t0)`` is its duration there."""
+    (pa, qa), (pb, qb) = anchor_a, anchor_b
+    scale = (qb - qa) / (pb - pa)
+    return qa + (np.asarray(t_ns, dtype=np.float64) - pa) * scale
 
 
 # ------------------------------------------------------- multi-tracer views

@@ -5,9 +5,12 @@ byte-identical with tracing on (single box, fleet replay, and chaos).
 """
 import json
 
+import numpy as np
 import pytest
 
 from repro.core.config import ObsConfig, small_test_config
+from repro.core.elastic_kv import (ElasticKVCache, KVGeometry,
+                                   make_kv_taiji_config)
 from repro.core.metrics import FK_COMPRESSED, FK_NAMES, FK_ZERO, Metrics
 from repro.core.system import TaijiSystem
 from repro.fleet import chaos_trace, paper_trace
@@ -15,7 +18,8 @@ from repro.fleet.harness import build_fleet, replay_twice
 from repro.obs import (STAGE_NAMES, SpanTracer, export_chrome, render_prom,
                        stage_tree)
 from repro.obs.tracer import (ST_FAULT_MUTEX, ST_FAULT_TOTAL,
-                              ST_GUEST_ACCESS, TAG_READ)
+                              ST_GUEST_ACCESS, ST_KV_APPEND, ST_PIN_STEP,
+                              ST_SCHED_TASK, STAGES, TAG_READ, TAG_SWAPIN_PIN)
 
 
 def traced_cfg(**overrides):
@@ -62,6 +66,18 @@ def test_max_spans_bounds_retained_not_aggregates():
     assert tr.span_count == 12           # aggregates never drop
     assert len(list(tr.spans())) == 5    # retained store is bounded
     assert tr.dropped_spans == 7
+
+
+def test_retained_store_keeps_the_newest_spans():
+    tr = SpanTracer(cap=4, max_spans=6)
+    for i in range(15):                  # flushes at 4, 8, 12 and below
+        tr.push(ST_GUEST_ACCESS, 1000 + i, 7)
+    tr.flush()
+    assert tr.span_count == 15           # aggregates never drop
+    _, t0, dur, _, _ = tr.span_arrays()
+    assert t0.tolist() == [1000 + i for i in range(9, 15)]
+    assert [s[1] for s in tr.spans()] == t0.tolist()
+    assert tr.dropped_spans == 9 and set(dur.tolist()) == {7}
 
 
 def test_zero_duration_span_survives_flush():
@@ -145,6 +161,101 @@ def test_fault_subtree_telescopes_to_fault_total():
         assert self_sum == tree["fault_total"]["total_ns"]
     finally:
         s.close()
+
+
+KV_GEOM = KVGeometry(n_layers=2, kv_heads=2, head_dim=16, block_tokens=4)
+
+
+def _inside(inner, outer, slack_ns=0):
+    """Each (t0, dur) of ``inner`` lies within one span of ``outer``."""
+    lo = np.array([t for t, _ in outer])
+    hi = lo + np.array([d for _, d in outer])
+    return all(np.any((lo - slack_ns <= t) & (t + d <= hi + slack_ns))
+               for t, d in inner)
+
+
+def test_serving_config_traces_by_default():
+    assert make_kv_taiji_config(KV_GEOM, 6).obs.enabled
+    off = make_kv_taiji_config(KV_GEOM, 6, obs=ObsConfig())
+    assert not off.obs.enabled           # an explicit obs= wins
+    assert not small_test_config().obs.enabled
+
+
+def test_served_path_stages_nest_and_partition():
+    """Six KV sequences of three blocks over six physical blocks: appends
+    open blocks by reclaiming synchronously (kv_alloc), and pinning each
+    sequence swaps its blocks back in, reclaiming inside the swap-in's
+    slot allocation (swap_in_alloc). The pin_step and kv_append subtrees'
+    self-times add up to their roots' totals, and each new span lies
+    inside one span of its declared parent."""
+    system = TaijiSystem(make_kv_taiji_config(KV_GEOM, 6, overcommit=2.0))
+    try:
+        cache = ElasticKVCache(KV_GEOM, system)
+        rng = np.random.default_rng(0)
+        for sid in range(6):
+            cache.create_sequence(sid)
+            for _ in range(12):
+                cache.append_kv(sid, rng.standard_normal(
+                    (2, 2, 2, 16)).astype(np.float16))
+        for sid in range(6):
+            with cache.prepare_step([sid]):
+                pass
+        tree = stage_tree([system.tracer])
+        assert tree["pin_step"]["count"] == 6
+        assert tree["kv_append"]["count"] == 6 * 12
+        assert tree["kv_alloc"]["count"] == 6 * 3
+        for name in ("swap_in_lock", "swap_in", "swap_in_alloc",
+                     "backend_load", "swap_out"):
+            assert tree[name]["count"] > 0, name
+        assert tree["swap_in"]["by_tag"].keys() == {TAG_SWAPIN_PIN}
+        parent = dict(STAGES)
+
+        def lineage(name):
+            while name is not None:
+                yield name
+                name = parent[name]
+
+        for root in ("pin_step", "kv_append"):
+            sub = [n for n in tree if root in lineage(n)]
+            assert sum(tree[n]["self_ns"] for n in sub) == \
+                tree[root]["total_ns"], root
+        stage, t0, dur, _, _ = system.tracer.span_arrays()
+
+        def spans(name):
+            sel = stage == STAGE_NAMES.index(name)
+            return list(zip(t0[sel].tolist(), dur[sel].tolist()))
+
+        for child in ("swap_in_lock", "swap_in", "swap_in_alloc",
+                      "backend_load", "kv_alloc"):
+            assert _inside(spans(child), spans(parent[child])), child
+        # synchronous reclaim ran inside both allocations
+        outs = spans("swap_out")
+        assert any(_inside([s], spans("kv_alloc")) for s in outs)
+        assert any(_inside([s], spans("swap_in_alloc")) for s in outs)
+    finally:
+        system.close()
+
+
+def test_snapshot_stage_deltas():
+    m = Metrics()
+    assert "stages" not in m.snapshot()           # no tracer, no stages
+    m.tracer = SpanTracer(cap=8)
+    m.tracer.push(ST_PIN_STEP, 0, 500)
+    a = m.snapshot()["stages"]
+    for i in range(20):                           # crosses ring flushes
+        m.tracer.push(ST_PIN_STEP, 10 + i, 100 + i)
+        m.tracer.push(ST_KV_APPEND, 10 + i, 3, i % 2)
+    m.tracer.push(ST_SCHED_TASK, 50, 40, 2)
+    b = m.snapshot()["stages"]
+    assert a == {"pin_step": {"count": 1, "total_ns": 500,
+                              "by_tag": {0: {"count": 1, "total_ns": 500}}}}
+    assert b["pin_step"]["count"] - a["pin_step"]["count"] == 20
+    assert b["pin_step"]["total_ns"] - a["pin_step"]["total_ns"] == \
+        sum(100 + i for i in range(20))
+    assert b["kv_append"]["by_tag"] == {0: {"count": 10, "total_ns": 30},
+                                        1: {"count": 10, "total_ns": 30}}
+    assert b["sched_task"] == {"count": 1, "total_ns": 40,
+                               "by_tag": {2: {"count": 1, "total_ns": 40}}}
 
 
 # --------------------------------------------------------- chrome export
