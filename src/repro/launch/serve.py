@@ -61,14 +61,8 @@ def make_decode_step(cfg: ArchConfig):
     the batch's block-table rows and lengths; it is donated, so the pool is
     updated in place. ``kv`` is what the step wrote into the pool.
     """
-    bt = cfg.kv_block_tokens
-
     def step(params, tokens, cache):
-        pos = cache["kv_len"]
-        logits, new = M.decode_step(params, cfg, tokens, cache)
-        blk = jnp.take_along_axis(cache["block_table"], (pos // bt)[:, None],
-                                  axis=1)[:, 0]
-        kv = new["kv_pool"][:, blk, pos % bt]           # (L, B, 2, KV, hd)
+        logits, kv, new = M.decode_step_kv(params, cfg, tokens, cache)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits, greedy, jnp.moveaxis(kv, 0, 1), new
 

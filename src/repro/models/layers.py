@@ -278,9 +278,9 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     scale = scale if scale is not None else hd ** -0.5
     g = Hq // Hkv
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(B, Hkv, g, hd)
-    # keep k/v in their storage dtype: upcasting them here made XLA hoist
-    # a full-pool fp32 convert + gather out of the layer scan (77 GB/step
-    # measured on decode_32k -- see EXPERIMENTS.md §Perf cell A)
+    # keep k/v in their storage dtype: XLA can hoist an fp32 upcast of them
+    # out of the layer scan as a convert of the whole pool, which then
+    # moves twice the pool's bytes every step
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k,
                    preferred_element_type=jnp.float32)
     if kv_len is not None:

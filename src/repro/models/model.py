@@ -424,36 +424,42 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
     return cache
 
 
-def _paged_kv_write(pool_l: jnp.ndarray, block_table: jnp.ndarray,
-                    pos: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                    bt: int) -> jnp.ndarray:
-    """Write one token's K/V into the paged pool.
+def _paged_kv_read(pool: jnp.ndarray, layer, block_table: jnp.ndarray,
+                   pos: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
+    """One layer's K/V for the batch, with this token's K/V at ``pos``.
 
-    pool_l: (n_blocks, bt, 2, KV, hd) [global layout] or
-    (B, mbs, bt, 2, KV, hd) [per_seq layout]; pos: (B,) absolute
-    positions; k/v: (B, KV, hd).
+    pool: (L, n_blocks, bt, 2, KV, hd) [global layout] or
+    (L, B, mbs, bt, 2, KV, hd) [per_seq layout], read only; layer: scalar;
+    block_table: (B, mbs); pos: (B,) absolute positions; kv: (B, 2, KV, hd)
+    in the pool's dtype. Returns the sequence-major view
+    (B, mbs * bt, 2, KV, hd): what the pool holds for these rows once
+    :func:`_paged_kv_commit` has written ``kv``.
     """
-    B = pos.shape[0]
-    blk = jnp.take_along_axis(block_table, (pos // bt)[:, None], axis=1)[:, 0]
-    slot = pos % bt
-    kv = jnp.stack([k, v], axis=1).astype(pool_l.dtype)      # (B, 2, KV, hd)
-    if pool_l.ndim == 6:                     # per_seq layout
-        return pool_l.at[jnp.arange(B), blk, slot].set(kv)
-    return pool_l.at[blk, slot].set(kv)
-
-
-def _paged_kv_read(pool_l: jnp.ndarray, block_table: jnp.ndarray,
-                   bt: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Gather a sequence-major KV view: (B, S_max, KV, hd) x2."""
-    if pool_l.ndim == 6:                     # per_seq: batch-aligned gather
-        B, mbs = block_table.shape
-        idx = block_table.reshape(B, mbs, 1, 1, 1, 1)
-        gathered = jnp.take_along_axis(pool_l, idx, axis=1)
+    if pool.ndim == 7:                       # per_seq: batch-aligned gather
+        B = block_table.shape[0]
+        blocks = pool[layer, jnp.arange(B)[:, None], block_table]
     else:
-        gathered = pool_l[block_table]       # (B, mbs, bt, 2, KV, hd)
-    B, mbs, _, _, KV, hd = gathered.shape
-    seq = gathered.reshape(B, mbs * bt, 2, KV, hd)
-    return seq[:, :, 0], seq[:, :, 1]
+        blocks = pool[layer, block_table]    # (B, mbs, bt, 2, KV, hd)
+    B, mbs, bt = blocks.shape[:3]
+    seq = blocks.reshape(B, mbs * bt, *blocks.shape[3:])
+    at_pos = jnp.arange(mbs * bt)[None, :] == pos[:, None]
+    return jnp.where(at_pos[:, :, None, None, None], kv[:, None], seq)
+
+
+def _paged_kv_commit(pool: jnp.ndarray, block_table: jnp.ndarray,
+                     pos: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
+    """Write every layer's new K/V, kv: (L, B, 2, KV, hd), into the pool
+    at ``pos``: one scatter, in place where the pool is donated. Each row
+    of a batch owns its blocks, so no two updates hit one slot."""
+    L, B = kv.shape[:2]
+    bt = pool.shape[-4]
+    blk = jnp.take_along_axis(block_table, (pos // bt)[:, None], axis=1)[:, 0]
+    layer = jnp.arange(L)[:, None]
+    if pool.ndim == 7:                       # per_seq: (layer, row, block, slot)
+        idx = (layer, jnp.arange(B)[None], blk[None], (pos % bt)[None])
+    else:                                    # global: (layer, block, slot)
+        idx = (layer, blk[None], (pos % bt)[None])
+    return pool.at[idx].set(kv, unique_indices=True)
 
 
 def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
@@ -467,10 +473,28 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     used when replaying a multimodal prefix (vision patches) through the
     decode path.
     """
+    logits, _, new_cache = decode_step_kv(params, cfg, tokens, cache,
+                                          mrope_pos, input_embeds)
+    return logits, new_cache
+
+
+def decode_step_kv(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
+                   cache: Dict[str, jnp.ndarray],
+                   mrope_pos: Optional[jnp.ndarray] = None,
+                   input_embeds: Optional[jnp.ndarray] = None
+                   ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray],
+                              Dict[str, jnp.ndarray]]:
+    """:func:`decode_step` that also returns the K/V it wrote:
+    -> (logits (B, V), kv (La, B, 2, KV, hd) or None, cache').
+
+    The pool never passes through the layer scan: each layer gathers its
+    rows' blocks from the whole pool, which it only reads, and the scan
+    stacks each layer's new K/V (``kv``, in the pool's dtype); one scatter
+    after the scan writes them all into the pool.
+    """
     cdt = _dtype(cfg.compute_dtype)
     B = tokens.shape[0]
     hd = cfg.head_dim_
-    bt = cfg.kv_block_tokens
     pos = cache["kv_len"]                                    # (B,)
 
     if input_embeds is not None:
@@ -488,7 +512,9 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     else:
         cos = sin = None
 
-    def attn_decode(h2, layer_p, pool_l):
+    pool = cache.get("kv_pool")
+
+    def attn_decode(h2, layer_p, layer):
         q = h2 @ layer_p["wq"]
         k = h2 @ layer_p["wk"]
         v = h2 @ layer_p["wv"]
@@ -496,7 +522,7 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
             q, k, v = q + layer_p["bq"], k + layer_p["bk"], v + layer_p["bv"]
         # decode attention is pure-DP over the batch: heads stay replicated
         # per device so the (KV, group) factorization never reshards the
-        # batch-local KV pool (EXPERIMENTS.md §Perf cell A)
+        # batch-local K/V
         q = shard_ctx.act(q.reshape(B, 1, cfg.n_heads, hd))
         k = shard_ctx.act(k.reshape(B, 1, cfg.n_kv_heads, hd))
         v = shard_ctx.act(v.reshape(B, 1, cfg.n_kv_heads, hd))
@@ -505,29 +531,30 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
             k = rms_norm(k, layer_p["k_norm"], cfg.norm_eps)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        pool_l = shard_ctx.act(_paged_kv_write(
-            pool_l, cache["block_table"], pos, k[:, 0], v[:, 0], bt))
-        ks, vs = _paged_kv_read(pool_l, cache["block_table"], bt)
-        o = decode_attention(q, ks.astype(cdt), vs.astype(cdt),
-                             kv_len=pos + 1)
+        kv = jnp.stack([k[:, 0], v[:, 0]], axis=1).astype(pool.dtype)
+        seq = shard_ctx.act(_paged_kv_read(pool, layer, cache["block_table"],
+                                           pos, kv))
+        o = decode_attention(q, seq[:, :, 0].astype(cdt),
+                             seq[:, :, 1].astype(cdt), kv_len=pos + 1)
         o = o.reshape(B, cfg.n_heads * hd)
-        return o @ layer_p["wo"], pool_l
+        return o @ layer_p["wo"], kv
 
     new_cache = dict(cache)
+    kvs = None
 
     if cfg.family == "hybrid":
         g = cfg.hybrid_group
         G = cfg.n_layers // g
 
         def group_step(x1, xs):
-            group_p, pool_l, conv_g, ssm_g = xs
+            group_p, layer, conv_g, ssm_g = xs
             group_p = _cast(group_p, cdt)
             mi = 0
             conv_out, ssm_out = [], []
             for j in range(g):
                 h = rms_norm(x1, group_p["ln_mix"][j], cfg.norm_eps)
                 if j == cfg.attn_index:
-                    h, pool_l = attn_decode(h, group_p["attn"], pool_l)
+                    h, kv = attn_decode(h, group_p["attn"], layer)
                 else:
                     mp = jax.tree.map(lambda w: w[mi], group_p["mamba"])
                     h, cs, ss = mamba_decode_step(
@@ -545,16 +572,15 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
                     ml = jax.tree.map(lambda w: w[j // 2], group_p["mlp"])
                     h = swiglu(h, ml["w_gate"], ml["w_up"], ml["w_down"])
                 x1 = x1 + h
-            return x1, (pool_l, jnp.stack(conv_out), jnp.stack(ssm_out))
+            return x1, (kv, jnp.stack(conv_out), jnp.stack(ssm_out))
 
-        x, (pools, convs, ssms) = lax.scan(
+        x, (kvs, convs, ssms) = lax.scan(
             group_step, x,
-            (params["layers"], cache["kv_pool"],
+            (params["layers"], jnp.arange(G),
              cache["conv_state"].reshape(G, g - 1, B, cfg.mamba.d_conv - 1,
                                          cfg.d_inner),
              cache["ssm_state"].reshape(G, g - 1, B, cfg.d_inner,
                                         cfg.mamba.d_state)))
-        new_cache["kv_pool"] = pools
         new_cache["conv_state"] = convs.reshape(cache["conv_state"].shape)
         new_cache["ssm_state"] = ssms.reshape(cache["ssm_state"].shape)
 
@@ -576,22 +602,21 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     else:
         m = cfg.moe
         has_layer0 = m is not None and m.first > 0 and "layer0" in params
-        pool = cache["kv_pool"]
-        pool_rest = pool[1:] if has_layer0 else pool
+        first = 1 if has_layer0 else 0
         if has_layer0:
             p0 = _cast(params["layer0"], cdt)
             h = rms_norm(x, p0["ln1"], cfg.norm_eps)
-            h, pool0 = attn_decode(h, p0["attn"], pool[0])
+            h, kv0 = attn_decode(h, p0["attn"], 0)
             x = x + h
             h = rms_norm(x, p0["ln2"], cfg.norm_eps)
             x = x + swiglu(h, p0["mlp"]["w_gate"], p0["mlp"]["w_up"],
                            p0["mlp"]["w_down"])
 
         def layer_step(x1, xs):
-            layer_p, pool_l = xs
+            layer_p, layer = xs
             layer_p = _cast(layer_p, cdt)
             h = rms_norm(x1, layer_p["ln1"], cfg.norm_eps)
-            h, pool_l = attn_decode(h, layer_p["attn"], pool_l)
+            h, kv = attn_decode(h, layer_p["attn"], layer)
             x1 = x1 + h
             h = rms_norm(x1, layer_p["ln2"], cfg.norm_eps)
             if m is not None:
@@ -600,16 +625,21 @@ def decode_step(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
             else:
                 ml = layer_p["mlp"]
                 h = swiglu(h, ml["w_gate"], ml["w_up"], ml["w_down"])
-            return x1 + h, pool_l
+            return x1 + h, kv
 
-        x, pools = lax.scan(layer_step, x, (params["layers"], pool_rest))
-        new_cache["kv_pool"] = (jnp.concatenate([pool0[None], pools], axis=0)
-                                if has_layer0 else pools)
+        x, kvs = lax.scan(layer_step, x,
+                          (params["layers"],
+                           jnp.arange(first, pool.shape[0])))
+        if has_layer0:
+            kvs = jnp.concatenate([kv0[None], kvs], axis=0)
 
+    if kvs is not None:
+        new_cache["kv_pool"] = _paged_kv_commit(pool, cache["block_table"],
+                                                pos, kvs)
     x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     logits = logits_from_hidden(params, cfg, x)
     new_cache["kv_len"] = pos + 1
-    return logits, new_cache
+    return logits, kvs, new_cache
 
 
 # ================================================================= prefill

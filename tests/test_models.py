@@ -1,4 +1,6 @@
 """Per-arch smoke tests (reduced configs) + decode/forward consistency."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,10 +59,15 @@ def test_smoke_output_shapes(arch):
 
 @pytest.mark.parametrize("arch", ["qwen3-4b", "qwen2.5-32b", "granite-20b",
                                   "deepseek-moe-16b", "qwen3-moe-235b-a22b",
-                                  "falcon-mamba-7b", "jamba-1.5-large-398b"])
+                                  "falcon-mamba-7b", "jamba-1.5-large-398b",
+                                  "qwen3-4b:per_seq"])
 def test_decode_matches_forward(arch):
-    """Token-by-token paged decode == full teacher-forced forward."""
+    """Token-by-token paged decode == full teacher-forced forward
+    (``<arch>:per_seq``: through the per-sequence pool layout)."""
+    arch, _, layout = arch.partition(":")
     cfg = reduced_config(arch)
+    if layout:
+        cfg = dataclasses.replace(cfg, kv_pool_layout=layout)
     params = M.init_params(RNG, cfg)
     B, S = 2, 16
     tokens = jax.random.randint(RNG, (B, S), 0, cfg.vocab)

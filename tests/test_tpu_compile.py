@@ -9,6 +9,7 @@ JAX's persistent cache is off around the compiles (a compile for a
 described chip could be written to it but never read back).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,5 +109,11 @@ def test_full_width_decode_step_fits_one_chip(shape):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES, mem
-    # the donated pool comes back in place
-    assert mem.alias_size_in_bytes >= cache["kv_pool"].size * 2
+    # the donated pool comes back in place: no copy of it, no second pool
+    pool_bytes = cache["kv_pool"].size * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 8, mem
+    pool = "bf16[" + ",".join(map(str, cache["kv_pool"].shape)) + "]"
+    ops = re.findall(re.escape(pool) + r"\{[^}]*\} (copy|dynamic-update-slice)\(",
+                     compiled.as_text())
+    assert not ops, ops
