@@ -2,13 +2,14 @@
 
 These are the algorithm's needs, not what the implementation moves: a
 decode step needs its weights, the K/V of each row's real length and the
-K/V it writes; a swap-kernel call needs the rows it reads and writes.
+K/V it writes (its model family's module, ``bench/families/``, counts
+them); a swap-kernel call needs the rows it reads and writes.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, Sequence
+from typing import Dict, Optional, Sequence
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -24,54 +25,37 @@ def peaks(device_kind: str) -> Dict:
     return table[device_kind]
 
 
-def _dims(arch):
-    return (arch.vocab, arch.d_model, arch.n_layers, arch.n_heads,
-            arch.n_kv_heads, arch.head_dim_, arch.d_ff)
+def _family(arch, root):
+    from bench.cell import family_module
+
+    return family_module(arch.family, root)
 
 
-def layer_matmul_params(arch) -> int:
-    """Weights of one layer's matrix products (q, k, v, o, gate, up, down)."""
-    _, D, _, H, KV, hd, F = _dims(arch)
-    return D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
+def layer_matmul_params(arch, root: Optional[Path] = None) -> int:
+    """Weights of one layer's matrix products."""
+    return _family(arch, root).layer_matmul_params(arch)
 
 
-def param_count(arch) -> int:
-    V, D, L, H, KV, hd, _ = _dims(arch)
-    per_layer = layer_matmul_params(arch) + 2 * D
-    if arch.qkv_bias:
-        per_layer += (H + 2 * KV) * hd
-    if arch.qk_norm:
-        per_layer += 2 * hd
-    return V * D * (1 if arch.tie_embeddings else 2) + L * per_layer + D
+def param_count(arch, root: Optional[Path] = None) -> int:
+    return _family(arch, root).param_count(arch)
 
 
-def kv_token_bytes(arch, dtype_bytes: int = 2) -> int:
+def kv_token_bytes(arch, dtype_bytes: int = 2, root: Optional[Path] = None) -> int:
     """K and V of one token over all layers."""
-    return arch.n_layers * 2 * arch.n_kv_heads * arch.head_dim_ * dtype_bytes
+    return _family(arch, root).kv_token_bytes(arch, dtype_bytes)
 
 
-def decode_flops(arch, kv_lens: Sequence[int]) -> float:
+def decode_flops(arch, kv_lens: Sequence[int], root: Optional[Path] = None) -> float:
     """Model FLOPs of one decode step over rows whose caches hold
-    ``kv_lens`` tokens before the step: the products with every weight
-    matrix and the output head, and attention over kv_len + 1 positions."""
-    V, D, L, H, _, hd, _ = _dims(arch)
-    rows = len(kv_lens)
-    dense = 2.0 * (L * layer_matmul_params(arch) + D * V) * rows
-    attn = 4.0 * L * H * hd * float(sum(int(n) + 1 for n in kv_lens))
-    return dense + attn
+    ``kv_lens`` tokens before the step."""
+    return _family(arch, root).decode_flops(arch, kv_lens)
 
 
-def decode_bytes(arch, kv_lens: Sequence[int], dtype_bytes: int = 2) -> float:
-    """HBM bytes one decode step needs: every weight once (the embedding
-    table only for the rows' tokens, unless it is also the output head),
-    the K/V of each row's ``kv_len`` tokens, and the K/V it writes."""
-    V, D, _, _, _, _, _ = _dims(arch)
-    rows = len(kv_lens)
-    weights = param_count(arch) - V * D + rows * D
-    if arch.tie_embeddings:
-        weights += V * D                      # the head reads the whole table
-    kv = kv_token_bytes(arch, dtype_bytes) * (float(sum(int(n) for n in kv_lens)) + rows)
-    return weights * dtype_bytes + kv
+def decode_bytes(arch, kv_lens: Sequence[int], dtype_bytes: int = 2,
+                 root: Optional[Path] = None) -> float:
+    """HBM bytes one decode step needs over rows whose caches hold
+    ``kv_lens`` tokens before the step."""
+    return _family(arch, root).decode_bytes(arch, kv_lens, dtype_bytes)
 
 
 # swap kernels: blocks in the word layout (n, R, 128) uint32 = n rows of

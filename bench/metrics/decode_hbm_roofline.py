@@ -14,6 +14,7 @@ def read(rec):
     steps = traced_steps(rec)
     if not n or not steps or rec.peaks is None:
         return None
-    per_step = np.mean([counts.decode_bytes(rec.arch, s["kv_lens"]) for s in steps])
+    per_step = np.mean([counts.decode_bytes(rec.arch, s["kv_lens"], root=rec.cell.root)
+                        for s in steps])
     need_s = n * per_step / rec.peaks["hbm_bytes_per_s"]
     return 100.0 * need_s / rec.trace["module_s"][STEP_MODULE]

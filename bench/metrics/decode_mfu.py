@@ -11,5 +11,6 @@ def read(rec):
     steps = traced_steps(rec)
     if not steps or rec.peaks is None or not rec.trace["window_s"]:
         return None
-    flops = sum(counts.decode_flops(rec.arch, s["kv_lens"]) for s in steps)
+    flops = sum(counts.decode_flops(rec.arch, s["kv_lens"], root=rec.cell.root)
+                for s in steps)
     return 100.0 * flops / (rec.trace["window_s"] * rec.peaks["bf16_flops_per_s"])

@@ -1,2 +1,3 @@
-"""Plain references, one module per model family, named by a config's
-``reference`` key. They import nothing of the system under test."""
+"""Plain references, one module per architecture, named by a
+configuration's ``reference`` key. They import nothing of the system
+under test."""

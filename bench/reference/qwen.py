@@ -35,7 +35,7 @@ def _round(x: jnp.ndarray, axis: int, quant: str) -> jnp.ndarray:
     raise ValueError(f"unknown quant {quant!r}")
 
 
-def _mm(x: jnp.ndarray, w: jnp.ndarray, quant: Optional[str]) -> jnp.ndarray:
+def mm(x: jnp.ndarray, w: jnp.ndarray, quant: Optional[str]) -> jnp.ndarray:
     """x (..., i) @ w (i, o) in float32."""
     w = w.astype(jnp.float32)
     if quant:
@@ -44,7 +44,7 @@ def _mm(x: jnp.ndarray, w: jnp.ndarray, quant: Optional[str]) -> jnp.ndarray:
     return jnp.einsum("...i,io->...o", x, w, precision=HI)
 
 
-def _rms(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
+def rms(x: jnp.ndarray, w: jnp.ndarray, eps: float) -> jnp.ndarray:
     return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w.astype(jnp.float32)
 
 
@@ -59,20 +59,23 @@ def _rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(cfg: Dict, quant: Optional[str], x: jnp.ndarray, p: Dict):
+def attention(cfg: Dict, quant: Optional[str], x: jnp.ndarray, ln: jnp.ndarray,
+              a: Dict) -> jnp.ndarray:
+    """x (T, D) plus its pre-norm attention block over the whole causal
+    sequence; ``cfg`` holds ``heads``, ``kv_heads``, ``head_dim``, ``eps``,
+    ``theta``, ``qkv_bias`` and ``qk_norm``."""
     T = x.shape[0]
     H, KV, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
     eps = cfg["eps"]
-    a = p["attn"]
-    h = _rms(x, p["ln1"], eps)
-    q, k, v = _mm(h, a["wq"], quant), _mm(h, a["wk"], quant), _mm(h, a["wv"], quant)
+    h = rms(x, ln, eps)
+    q, k, v = mm(h, a["wq"], quant), mm(h, a["wk"], quant), mm(h, a["wv"], quant)
     if cfg["qkv_bias"]:
         q = q + a["bq"].astype(jnp.float32)
         k = k + a["bk"].astype(jnp.float32)
         v = v + a["bv"].astype(jnp.float32)
     q, k, v = q.reshape(T, H, hd), k.reshape(T, KV, hd), v.reshape(T, KV, hd)
     if cfg["qk_norm"]:
-        q, k = _rms(q, a["q_norm"], eps), _rms(k, a["k_norm"], eps)
+        q, k = rms(q, a["q_norm"], eps), rms(k, a["k_norm"], eps)
     q, k = _rope(q, cfg["theta"]), _rope(k, cfg["theta"])
     g = H // KV
     qg = q.reshape(T, KV, g, hd)
@@ -80,11 +83,18 @@ def _layer(cfg: Dict, quant: Optional[str], x: jnp.ndarray, p: Dict):
     causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
     s = jnp.where(causal, s, -jnp.inf)
     o = jnp.einsum("kgts,skd->tkgd", jax.nn.softmax(s, -1), v, precision=HI)
-    x = x + _mm(o.reshape(T, H * hd), a["wo"], quant)
-    h = _rms(x, p["ln2"], eps)
-    m = p["mlp"]
-    u = jax.nn.silu(_mm(h, m["w_gate"], quant)) * _mm(h, m["w_up"], quant)
-    return x + _mm(u, m["w_down"], quant), None
+    return x + mm(o.reshape(T, H * hd), a["wo"], quant)
+
+
+def swiglu(quant: Optional[str], h: jnp.ndarray, m: Dict) -> jnp.ndarray:
+    """The SwiGLU MLP ``m`` (w_gate, w_up, w_down) of h (T, D)."""
+    u = jax.nn.silu(mm(h, m["w_gate"], quant)) * mm(h, m["w_up"], quant)
+    return mm(u, m["w_down"], quant)
+
+
+def _layer(cfg: Dict, quant: Optional[str], x: jnp.ndarray, p: Dict):
+    x = attention(cfg, quant, x, p["ln1"], p["attn"])
+    return x + swiglu(quant, rms(x, p["ln2"], cfg["eps"]), p["mlp"]), None
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -96,9 +106,9 @@ def _logits_at(cfg_key, quant, params: Dict, tokens: jnp.ndarray,
     if quant:
         x = _round(x, -1, quant)
     x, _ = lax.scan(functools.partial(_layer, cfg, quant), x, params["layers"])
-    h = _rms(x[positions], params["final_norm"], cfg["eps"])
+    h = rms(x[positions], params["final_norm"], cfg["eps"])
     head = emb.T if cfg["tied"] else params["lm_head"]
-    return _mm(h, head, quant)
+    return mm(h, head, quant)
 
 
 def logits_at(config: Dict, params: Dict, tokens, positions,

@@ -80,8 +80,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
     from bench.weights import make_params
 
     t_start = T_START if t_start is None else t_start
-    arch = arch or cells.arch_config(cell.config)
-    params = jax.block_until_ready(make_params(arch, seed))
+    arch = arch or cells.arch_config(cell.config, cell.root)
+    params = jax.block_until_ready(make_params(arch, seed, cell.root))
     traffic = SessionTraffic(cell.traffic, seed, arch.vocab)
     loop = ClosedLoop(arch, params, traffic, step=step, after_setup=after_setup)
     trace_dir = None
@@ -119,8 +119,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
             peaks=counts.peaks(device["kind"]) if red["chips"] else None,
             trace_span=loop.trace_s)
         metrics = {}
-        readers = cells.metric_readers()
-        for name in cells.declared_metrics(cell.name, "per_layer"):
+        readers = cells.metric_readers(cell.root)
+        for name in cells.declared_metrics(cell.name, "per_layer", cell.root.parent):
             mod = readers[name]
             value = mod.read(rec)
             if value is not None:
@@ -131,13 +131,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *, device: dict,
                                  "idle_gaps": red["idle_gaps"]})
     else:
         values = e2e.metrics(loop.requests, t_open, t_close, setup_s)
-        wanted = cells.declared_metrics(cell.name, "end_to_end")
+        wanted = cells.declared_metrics(cell.name, "end_to_end", cell.root.parent)
         result.update(metrics={k: {"value": values[k], "unit": e2e.UNITS[k]}
                                for k in wanted},
                       device=device)
 
     picked = check.sample(loop.requests, t_close, seed)
-    gaps = check.logit_gaps(cells.reference_module(cell.config), cell.config,
+    gaps = check.logit_gaps(cells.reference_module(cell.config, cell.root), cell.config,
                             params, loop.conv_tokens, picked, traffic.cap,
                             int(cell.traffic["output_tokens"]["hi"]), controls)
     numbers.update(gaps)

@@ -1,4 +1,5 @@
 import copy
+import dataclasses
 import os
 import sys
 
@@ -14,16 +15,12 @@ REDUCED_LOGIT_ERR_MAX = 0.09
 
 
 def reduced(cell):
-    """``cell`` at CPU-test widths and a few sessions; its architecture
-    flags, dtype, traffic shape and limits stay, but for the limit of
-    ``logit_err_max``."""
-    from bench.cell import Cell
+    """``cell`` at its family's CPU-test widths and a few sessions; its
+    architecture flags, dtype, traffic shape and limits stay, but for the
+    limit of ``logit_err_max``."""
+    from bench.cell import family_module
 
-    cfg = copy.deepcopy(cell.config)
-    cfg.update(hidden_size=128, intermediate_size=256, num_hidden_layers=3,
-               num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-               vocab_size=512)
-    cfg["assumed"] = dict(cfg["assumed"], kv_block_tokens=8)
+    cfg = family_module(cell.config["family"], cell.root).reduced(copy.deepcopy(cell.config))
     tr = copy.deepcopy(cell.traffic)
     tr.update(clients=4, sessions=16, context_cap_tokens=64)
     tr["history"] = dict(tr["history"], hi=56)
@@ -34,7 +31,7 @@ def reduced(cell):
     # 0.14-0.19, so the limit lies between them
     wl = copy.deepcopy(cell.workload)
     wl["limits"]["logit_err_max"] = REDUCED_LOGIT_ERR_MAX
-    return Cell(cell.name, wl, cfg, tr)
+    return dataclasses.replace(cell, workload=wl, config=cfg, traffic=tr)
 
 
 @pytest.fixture

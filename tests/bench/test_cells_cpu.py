@@ -112,7 +112,7 @@ def test_flipped_byte_in_taiji_is_not_correct():
         assert res["checks"][name]["value"] <= res["checks"][name]["limit"]
 
 
-CONTROLS = ["qwen3-4b.chat-overcommit"]
+CONTROLS = ["qwen3-4b.chat-overcommit", "qwen2-0.5b.sessions-overcommit"]
 
 
 @pytest.mark.parametrize("name", CONTROLS)
