@@ -171,11 +171,12 @@ class ElasticKVCache:
 
     # --------------------------------------------------------------- writes
     def append_kv(self, seq_id: int, kv_token: np.ndarray) -> None:
-        """Append one token's KV (shape: [n_layers, 2, kv_heads, head_dim])."""
+        """Append one token's KV: [n_layers, 2, kv_heads, head_dim], or its
+        flat view [n_layers, 2 * kv_heads * head_dim] (the same bytes)."""
         g = self.geom
         expect = (g.n_layers, 2, g.kv_heads, g.head_dim)
-        if kv_token.shape != expect:
-            raise ValueError(f"kv shape {kv_token.shape} != {expect}")
+        if kv_token.shape not in (expect, (g.n_layers, 2 * g.kv_heads * g.head_dim)):
+            raise ValueError(f"kv shape {kv_token.shape} != {expect} or its flat view")
         tr = self._tr
         if tr is not None:
             t0 = _perf_ns()

@@ -87,7 +87,7 @@ class ShardingRules:
         tp_q = tp_if(cfg.n_heads and cfg.n_heads % tp_size == 0)
         tp_kv = tp_if(cfg.n_kv_heads and cfg.n_kv_heads % tp_size == 0)
         tp_ep = None
-        if cfg.moe is not None and cfg.moe.n_routed % tp_size == 0:
+        if cfg.moe is not None and cfg.moe.n_held % tp_size == 0:
             tp_ep = tp
 
         def rule(path, leaf) -> P:
@@ -191,9 +191,9 @@ class ShardingRules:
                 if len(v.shape) == 7:    # per_seq: (La, B, mbs, bt, 2, KV, hd)
                     out[k] = P(None, self._fit(v.shape[1], self.batch_axes),
                                None, None, None, None, None)
-                else:                    # global: (La, NB, bt, 2, KV, hd)
+                else:                    # global: (La, NB, bt, 2*KV*hd)
                     out[k] = P(None, self._fit(v.shape[1], self.batch_axes),
-                               None, None, None, None)
+                               None, None)
             elif k == "block_table":    # (B, mbs)
                 out[k] = P(b_ax, None)
             elif k == "kv_len":         # (B,)
@@ -227,6 +227,6 @@ class ShardingRules:
             vocab_ok=cfg.vocab % tp_size == 0,
             d_inner_ok=bool(cfg.d_inner and cfg.d_inner % tp_size == 0),
             experts_ok=bool(cfg.moe is not None
-                            and cfg.moe.n_routed % tp_size == 0),
+                            and cfg.moe.n_held % tp_size == 0),
             ffn_ok=bool(cfg.d_ff and cfg.d_ff % tp_size == 0),
         )

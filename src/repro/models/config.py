@@ -27,6 +27,19 @@ class MoEConfig:
     # dispatch collectives reduce to the expert-parallel all-to-all
     grouped_dispatch: bool = False
     min_group_tokens: int = 256   # fall back to global sort below this
+    # expert parallelism: the experts [first, stop) this device holds.
+    # The router still scores all n_routed; tokens routed elsewhere add
+    # nothing here (their experts live on other devices). None: all.
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_routed)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,6 +134,9 @@ class ArchConfig:
         if self.n_heads:
             assert self.n_kv_heads > 0
             assert self.n_heads % self.n_kv_heads == 0
+        if self.moe is not None:
+            lo, hi = self.moe.held_range
+            assert 0 <= lo < hi <= self.moe.n_routed
 
     # parameter count (for 6ND model-FLOPs in the roofline)
     def param_count(self) -> int:
@@ -152,7 +168,7 @@ class ArchConfig:
             if self.is_moe_layer(l):
                 m = self.moe
                 n += D * m.n_routed            # router
-                n += m.n_routed * 3 * D * m.d_ff_expert
+                n += m.n_held * 3 * D * m.d_ff_expert
                 n += m.n_shared * 3 * D * m.d_ff_expert
             elif self.d_ff:
                 n += 3 * D * self.d_ff         # swiglu mlp

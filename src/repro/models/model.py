@@ -7,10 +7,12 @@ over groups of ``hybrid_group`` layers (7 mamba + 1 attention, FFN
 alternating dense/MoE), keeping heterogeneity inside the scanned body.
 
 Decode uses a paged KV cache: per attention layer a block pool
-``(n_blocks, block_tokens, 2, kv_heads, head_dim)`` addressed through a
+``(n_blocks, block_tokens, 2 * kv_heads * head_dim)`` addressed through a
 ``(B, max_blocks)`` block table -- the device-side analogue of Taiji's
 block-table (EPT) indirection, and the structure the elastic KV manager
-swaps at MS granularity.
+swaps at MS granularity. A token's K/V is one flat row in the order
+``(2, kv_heads, head_dim)``, so the pool's minor dimension is as wide
+at every KV geometry and no step relays the pool out.
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ from .config import ArchConfig
 from .layers import (apply_rope, attention_block,
                      decode_attention, mrope_cos_sin, rms_norm, rope_angles,
                      swiglu)
-from .moe import moe_ffn
+from .moe import moe_decode, moe_ffn
 from .ssm import mamba_block, mamba_decode_step
 
 Params = Dict[str, Any]
@@ -78,17 +80,19 @@ def _init_mlp(key, cfg: ArchConfig, d_ff: int, n: int = 1) -> Params:
 
 
 def _init_moe(key, cfg: ArchConfig, n: int = 1) -> Params:
+    """The router over all ``n_routed`` experts and the weights of the
+    ``n_held`` experts this device holds."""
     m = cfg.moe
-    D, E, F = cfg.d_model, m.n_routed, m.d_ff_expert
+    D, E, Eh, F = cfg.d_model, m.n_routed, m.n_held, m.d_ff_expert
     dt = _dtype(cfg.param_dtype)
     ks = jax.random.split(key, 7)
     shape = lambda *s: (n, *s) if n > 1 else s
     std = 0.02
     p = {
         "router": jax.random.normal(ks[0], shape(D, E), dt) * std,
-        "w_gate": jax.random.normal(ks[1], shape(E, D, F), dt) * std,
-        "w_up": jax.random.normal(ks[2], shape(E, D, F), dt) * std,
-        "w_down": jax.random.normal(ks[3], shape(E, F, D), dt) * (std / math.sqrt(2 * cfg.n_layers)),
+        "w_gate": jax.random.normal(ks[1], shape(Eh, D, F), dt) * std,
+        "w_up": jax.random.normal(ks[2], shape(Eh, D, F), dt) * std,
+        "w_down": jax.random.normal(ks[3], shape(Eh, F, D), dt) * (std / math.sqrt(2 * cfg.n_layers)),
     }
     if m.n_shared:
         Fs = m.n_shared * F
@@ -410,7 +414,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
                 jnp.arange(mbs, dtype=jnp.int32)[None, :], (batch, 1))
         else:
             cache["kv_pool"] = jnp.zeros(
-                (spec.n_attn_layers, nb, bt, 2, cfg.n_kv_heads, cfg.head_dim_),
+                (spec.n_attn_layers, nb, bt, 2 * cfg.n_kv_heads * cfg.head_dim_),
                 dtype)
             # sequence i owns pool rows [i*mbs, (i+1)*mbs)
             cache["block_table"] = (jnp.arange(batch)[:, None] * mbs
@@ -428,7 +432,7 @@ def _paged_kv_read(pool: jnp.ndarray, layer, block_table: jnp.ndarray,
                    pos: jnp.ndarray, kv: jnp.ndarray) -> jnp.ndarray:
     """One layer's K/V for the batch, with this token's K/V at ``pos``.
 
-    pool: (L, n_blocks, bt, 2, KV, hd) [global layout] or
+    pool: (L, n_blocks, bt, 2 * KV * hd) [global layout] or
     (L, B, mbs, bt, 2, KV, hd) [per_seq layout], read only; layer: scalar;
     block_table: (B, mbs); pos: (B,) absolute positions; kv: (B, 2, KV, hd)
     in the pool's dtype. Returns the sequence-major view
@@ -439,9 +443,9 @@ def _paged_kv_read(pool: jnp.ndarray, layer, block_table: jnp.ndarray,
         B = block_table.shape[0]
         blocks = pool[layer, jnp.arange(B)[:, None], block_table]
     else:
-        blocks = pool[layer, block_table]    # (B, mbs, bt, 2, KV, hd)
+        blocks = pool[layer, block_table]    # (B, mbs, bt, 2 * KV * hd)
     B, mbs, bt = blocks.shape[:3]
-    seq = blocks.reshape(B, mbs * bt, *blocks.shape[3:])
+    seq = blocks.reshape(B, mbs * bt, *kv.shape[1:])
     at_pos = jnp.arange(mbs * bt)[None, :] == pos[:, None]
     return jnp.where(at_pos[:, :, None, None, None], kv[:, None], seq)
 
@@ -452,13 +456,15 @@ def _paged_kv_commit(pool: jnp.ndarray, block_table: jnp.ndarray,
     at ``pos``: one scatter, in place where the pool is donated. Each row
     of a batch owns its blocks, so no two updates hit one slot."""
     L, B = kv.shape[:2]
-    bt = pool.shape[-4]
+    blk_dims = 4 if pool.ndim == 7 else 2    # (bt, 2, KV, hd) or (bt, 2*KV*hd)
+    bt = pool.shape[-blk_dims]
     blk = jnp.take_along_axis(block_table, (pos // bt)[:, None], axis=1)[:, 0]
     layer = jnp.arange(L)[:, None]
     if pool.ndim == 7:                       # per_seq: (layer, row, block, slot)
         idx = (layer, jnp.arange(B)[None], blk[None], (pos % bt)[None])
     else:                                    # global: (layer, block, slot)
         idx = (layer, blk[None], (pos % bt)[None])
+        kv = kv.reshape(L, B, -1)
     return pool.at[idx].set(kv, unique_indices=True)
 
 
@@ -566,8 +572,7 @@ def decode_step_kv(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
                 h = rms_norm(x1, group_p["ln_ffn"][j], cfg.norm_eps)
                 if j % 2 == 1:
                     mo = jax.tree.map(lambda w: w[j // 2], group_p["moe"])
-                    h, _ = moe_ffn(h[:, None, :], mo, cfg)
-                    h = h[:, 0]
+                    h = moe_decode(h, mo, cfg)
                 else:
                     ml = jax.tree.map(lambda w: w[j // 2], group_p["mlp"])
                     h = swiglu(h, ml["w_gate"], ml["w_up"], ml["w_down"])
@@ -612,6 +617,14 @@ def decode_step_kv(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
             x = x + swiglu(h, p0["mlp"]["w_gate"], p0["mlp"]["w_up"],
                            p0["mlp"]["w_down"])
 
+        layers, experts = params["layers"], {}
+        if m is not None:
+            # the expert stacks stay whole, like the pool: the kernel reads
+            # each layer's held experts in place through the layer index
+            experts = _cast({n: layers["moe"][n] for n in ("w_gate", "w_up", "w_down")}, cdt)
+            layers = dict(layers, moe={n: w for n, w in layers["moe"].items()
+                                       if n not in experts})
+
         def layer_step(x1, xs):
             layer_p, layer = xs
             layer_p = _cast(layer_p, cdt)
@@ -620,16 +633,15 @@ def decode_step_kv(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
             x1 = x1 + h
             h = rms_norm(x1, layer_p["ln2"], cfg.norm_eps)
             if m is not None:
-                h, _ = moe_ffn(h[:, None, :], layer_p["moe"], cfg)
-                h = h[:, 0]
+                h = moe_decode(h, dict(layer_p["moe"], **experts), cfg,
+                               layer=layer - first)
             else:
                 ml = layer_p["mlp"]
                 h = swiglu(h, ml["w_gate"], ml["w_up"], ml["w_down"])
             return x1 + h, kv
 
         x, kvs = lax.scan(layer_step, x,
-                          (params["layers"],
-                           jnp.arange(first, pool.shape[0])))
+                          (layers, jnp.arange(first, pool.shape[0])))
         if has_layer0:
             kvs = jnp.concatenate([kv0[None], kvs], axis=0)
 

@@ -14,17 +14,29 @@ sort-based (dropping-above-capacity) formulation:
 Under pjit the sort/gather/scatter become XLA collectives when tokens are
 data-sharded and experts are model-sharded (expert parallelism); the
 roofline table attributes those bytes to the collective term.
+
+A device may hold only some of the experts (``MoEConfig.held``, expert
+parallelism's share): the router still scores all ``n_routed``, the
+top-k gates are renormalised over the global top-k, and only the held
+experts' part of the result is computed here; assignments to experts
+held elsewhere add nothing. The decode step's expert layer
+(:func:`moe_decode`) is dropless and runs the held experts through the
+``expert_swiglu`` Pallas kernel, which reads only the experts that
+received a token.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro import shard_ctx
+from repro.kernels import ops
+from repro.kernels.expert_swiglu import ROW_TILE, expert_swiglu
 
 from .config import ArchConfig, MoEConfig
+from .layers import swiglu
 
 
 def router_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int
@@ -42,6 +54,56 @@ def router_topk(x: jnp.ndarray, w_router: jnp.ndarray, top_k: int
     return gates, idx, aux
 
 
+def _held_ids(idx: jnp.ndarray, m: MoEConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Global expert ids (T, k) -> flat local ids among the held
+    experts, with ``n_held`` for an expert held elsewhere, and the flat
+    mask of held assignments."""
+    lo, _ = m.held_range
+    local = idx.reshape(-1) - lo
+    held = (local >= 0) & (local < m.n_held)
+    return jnp.where(held, local, m.n_held), held
+
+
+def moe_decode(x: jnp.ndarray, p: dict, cfg: ArchConfig,
+               layer: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """The decode step's expert layer: x (T, D) -> (T, D), the held
+    experts' part of it, dropless.
+
+    Every assignment to a held expert is computed (no capacity). Each
+    held expert's rows start on a row tile of the ``expert_swiglu``
+    kernel, so an expert its rows reach is read once, and one no row
+    reaches is not read; assignments to experts held elsewhere get no
+    row. Shared experts are added as in :func:`moe_ffn`. With ``layer``,
+    ``p``'s expert weights are the whole stack of layers, read in place
+    at that index; without, one layer's."""
+    m: MoEConfig = cfg.moe
+    T, D = x.shape
+    k, G = m.top_k, m.n_held
+    gates, idx, _ = router_topk(x, p["router"], k)
+    flat_e, held = _held_ids(idx, m)                  # (T*k,)
+    token = jnp.arange(T * k) // k
+    # each assignment's row: its expert's tile-aligned start + its rank
+    onehot = flat_e[:, None] == jnp.arange(G + 1)[None, :]
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0), flat_e[:, None], 1)[:, 0] - 1
+    sizes = jnp.sum(onehot, 0)[:G].astype(jnp.int32)
+    tiles = -(-sizes // ROW_TILE) * ROW_TILE
+    start = jnp.cumsum(tiles) - tiles
+    rows = -(-(T * min(k, G) + G * (ROW_TILE - 1)) // ROW_TILE) * ROW_TILE
+    dest = jnp.where(held, start[jnp.minimum(flat_e, G - 1)] + rank, rows)
+    xs = jnp.zeros((rows, D), x.dtype).at[dest].set(x[token], mode="drop")
+    w = [p[n] if layer is not None else p[n][None]
+         for n in ("w_gate", "w_up", "w_down")]
+    y = expert_swiglu(xs, *w, tiles, 0 if layer is None else layer,
+                      interpret=ops.interpret_mode())
+    y = y[jnp.minimum(dest, rows - 1)].astype(jnp.float32)
+    contrib = jnp.where(held[:, None], y * gates.reshape(-1)[:, None], 0.0)
+    out = jnp.zeros((T, D), jnp.float32).at[token].add(contrib)
+    if m.n_shared:
+        out = out + swiglu(x, p["shared_gate"], p["shared_up"],
+                           p["shared_down"]).astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
 def _dispatch_tokens(xt: jnp.ndarray, p: dict, cfg: ArchConfig,
                      constrain: bool = True
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -51,23 +113,24 @@ def _dispatch_tokens(xt: jnp.ndarray, p: dict, cfg: ArchConfig,
     """
     m: MoEConfig = cfg.moe
     T, D = xt.shape
-    E, k = m.n_routed, m.top_k
+    k = m.top_k
     # capacity with a dropless floor for small token counts (decode steps
     # are exact; large training/prefill batches use capacity-factor drops)
-    C = min(max(int(T * k / E * m.capacity_factor), 64), T)
+    C = min(max(int(T * k / m.n_routed * m.capacity_factor), 64), T)
 
     gates, idx, aux = router_topk(xt, p["router"], k)
 
-    # ---- sort assignments by expert ------------------------------------
-    flat_e = idx.reshape(-1)                          # (T*k,)
+    # ---- sort assignments by held expert (others last, dropped) ---------
+    flat_e, _ = _held_ids(idx, m)                     # (T*k,)
+    E = m.n_held
     flat_t = jnp.repeat(jnp.arange(T), k)
     flat_g = gates.reshape(-1)
     order = jnp.argsort(flat_e, stable=True)
     se, st, sg = flat_e[order], flat_t[order], flat_g[order]
     # position within each expert's run
-    starts = jnp.searchsorted(se, jnp.arange(E), side="left")
+    starts = jnp.searchsorted(se, jnp.arange(E + 1), side="left")
     pos = jnp.arange(T * k) - starts[se]
-    keep = pos < C
+    keep = (pos < C) & (se < E)
 
     # ---- scatter into the (E, C) slot table ----------------------------
     slot = jnp.where(keep, se * C + pos, E * C)       # drops -> scratch slot

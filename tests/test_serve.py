@@ -111,9 +111,10 @@ def test_decode_step_donates_the_pool():
         params, jnp.zeros((2,), jnp.int32), cache)
     assert pool.is_deleted()                 # donated: updated in place
     assert kv.shape == (2, 2, 2, cfg.n_kv_heads, cfg.head_dim_)
-    got = np.array(new["kv_pool"])
-    np.testing.assert_array_equal(got[:, table[0, 0], 0], np.asarray(kv[0]))
-    np.testing.assert_array_equal(got[:, table[1, 1], 3], np.asarray(kv[1]))
+    got = np.array(new["kv_pool"])           # a token's K/V is one flat row
+    assert got.shape[-1] == 2 * cfg.n_kv_heads * cfg.head_dim_
+    np.testing.assert_array_equal(got[:, table[0, 0], 0], np.asarray(kv[0]).reshape(2, -1))
+    np.testing.assert_array_equal(got[:, table[1, 1], 3], np.asarray(kv[1]).reshape(2, -1))
     got[:, table[0, 0], 0] = 0
     got[:, table[1, 1], 3] = 0
     assert not got.any()                     # nothing else was written
@@ -211,7 +212,7 @@ def _ref_step(w, cfg, pool, table, pos, toks, kv_step):
         kv_ref[:, l, 1] = (h @ a["wv"]).reshape(B, KV, hd)
         o = np.zeros((B, H, hd), np.float32)
         for b in range(B):
-            pool[l, table[b, pos[b] // bt], pos[b] % bt] = kv_step[b, l]
+            pool[l, table[b, pos[b] // bt], pos[b] % bt] = kv_step[b, l].reshape(-1)
             blocks = pool[l, table[b, :pos[b] // bt + 1]].astype(np.float32)
             seq = blocks.reshape(-1, 2, KV, hd)[:pos[b] + 1]
             for hh in range(H):
